@@ -18,6 +18,10 @@ from repro.config.base import FLConfig
 from repro.core import run_method
 from repro.fl.client import build_fl_clients
 from repro.fl.network import WirelessNetwork
+from repro.launch.compile_cache import enable_compile_cache
+
+# every harness imports this module: one compile-cache rule for all
+enable_compile_cache()
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 

@@ -18,7 +18,7 @@ from repro.config import get_arch
 from repro.config.base import INPUT_SHAPES, TrainConfig
 from repro.launch import steps as steps_lib
 from repro.launch.mesh import make_production_mesh
-from repro.roofline import analyze_hlo, roofline_terms
+from repro.roofline import TPU_V5E, analyze_hlo, roofline_terms
 from repro.sharding import (batch_specs, decode_state_specs, named_shardings,
                             param_specs)
 from repro.sharding.hints import set_mesh
@@ -50,7 +50,7 @@ def run(arch, shape_name, fsdp: bool):
     terms = roofline_terms(
         hlo_flops=hlo["dot_flops"],
         hbm_bytes=0.0,
-        collective_bytes=hlo["collective_wire_bytes"], chips=1)
+        collective_bytes=hlo["collective_wire_bytes"], chips=1, hw=TPU_V5E)
     per_tok = terms["collective_s"] / N_STEPS
     print(f"[serve_loop] {arch} {shape_name} fsdp={fsdp}: "
           f"collective {terms['collective_s']:.4f}s / {N_STEPS} steps "

@@ -195,8 +195,7 @@ def staleness_weighted_merge(global_params, stacked, alphas, *,
     ``use_kernel=True`` routes through the folded Pallas fedagg kernel
     (``fedagg_fold_pytree``): the same implicit-row-0 formulation on
     the flattened (K, P) buffer — no (K+1, ...) concatenated copy
-    there either.  The kernel runs interpret-mode on CPU and compiled
-    on TPU; the store-backed fused window step dispatches the SAME
+    there either.  The store-backed fused window step dispatches the SAME
     program on the same flattened buffer, so kernel-path histories are
     bit-identical between the dict and store snapshot paths.
     """

@@ -84,6 +84,7 @@ def run_fedavg(trainer, network, fl: FLConfig, *, use_kernel_agg: bool = False,
             if fl.target_accuracy and acc >= fl.target_accuracy:
                 break
     run_span.end()
+    hist.meta.update(eng.run_meta())
     tel.summarize_into(hist.meta)
     return hist
 
@@ -170,6 +171,7 @@ def run_tifl(trainer, network, fl: FLConfig, *, use_kernel_agg: bool = False,
             if fl.target_accuracy and acc >= fl.target_accuracy:
                 break
     run_span.end()
+    hist.meta.update(eng.run_meta())
     tel.summarize_into(hist.meta)
     return hist
 
@@ -357,5 +359,6 @@ def run_fedprox(trainer, network, fl: FLConfig, *, prox_mu: float = 0.01,
             if fl.target_accuracy and acc >= fl.target_accuracy:
                 break
     run_span.end()
+    hist.meta.update(eng.run_meta())
     tel.summarize_into(hist.meta)
     return hist

@@ -32,6 +32,7 @@ from repro.core.aggregation import (aggregate_or_keep,
                                     staleness_merge_coefficients,
                                     staleness_weighted_merge,
                                     weighted_average_stacked)
+from repro.kernels.ops import resolve_interpret
 from repro.obs import flstats
 from repro.obs import telemetry as obs
 
@@ -45,8 +46,12 @@ class BatchedClientEngine:
                  force_looped: bool = False, pad_cohorts: bool = True):
         self.trainer = trainer
         self.use_kernel_agg = use_kernel_agg
-        self.interpret = interpret
+        # resolved once: Pallas interpret mode only on a CPU backend
+        self.interpret = resolve_interpret(interpret)
         self.force_looped = force_looped
+        # batched paths the trainer refused at run time (it raised
+        # NotImplementedError and the engine fell back to looping)
+        self.fallbacks: list = []
         # pad cohort size up to a power of two so jit retraces O(log C)
         # distinct shapes instead of one per cohort size; pad rows are
         # duplicates of the last client and are sliced off again.
@@ -99,6 +104,7 @@ class BatchedClientEngine:
                 return stacked, sizes
             except NotImplementedError:
                 self._can_batch = False
+                self.fallbacks.append("train_clients")
         outs = [self.trainer.local_train(params, c, rnd_seed=rnd_seed)
                 for c in ids]
         stacked = jax.tree_util.tree_map(
@@ -137,12 +143,21 @@ class BatchedClientEngine:
                 return stacked, sizes
             except NotImplementedError:
                 self._can_cohort = False
+                self.fallbacks.append("train_cohort")
         outs = [self.trainer.local_train(p0, c, rnd_seed=s)
                 for p0, c, s in zip(starts, ids, seeds)]
         stacked = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *[p for p, _ in outs])
         sizes = np.asarray([s for _, s in outs], np.float32)
         return stacked, sizes
+
+    def run_meta(self) -> dict:
+        """What actually ran, for ``RunHistory.meta``: whether the
+        Pallas merge ran in interpret mode (None without kernel agg)
+        and which batched paths fell back to the per-client loop."""
+        return {"kernel_interpret": (self.interpret if self.use_kernel_agg
+                                     else None),
+                "engine_fallbacks": list(self.fallbacks)}
 
     # -- aggregation ----------------------------------------------------
     def aggregate(self, stacked, weights):
@@ -205,8 +220,8 @@ class BatchedClientEngine:
         padded rows ride through the merge with coefficient 0 instead
         of being sliced off, so there is no post-hoc host repack.  The
         merge dispatches the folded Pallas fedagg kernel when the
-        engine was built with ``use_kernel_agg`` (interpret-mode on
-        CPU, compiled on TPU) — the same program the dict path runs.
+        engine was built with ``use_kernel_agg`` — the same program the
+        dict path runs.
         Returns ``(new_params, new_global_flat)``.
 
         Row format is the STORE's concern: under ``quant_bits=8`` the
@@ -250,6 +265,7 @@ class BatchedClientEngine:
                         params, **merge_kw)
             except NotImplementedError:
                 self._can_cohort = False
+                self.fallbacks.append("train_window")
         # looped fallback (trainers without local_train_cohort): rows
         # still merge + scatter through the store's fused program.
         with tel.span("window.train", cohort=n, looped=True):

@@ -144,5 +144,6 @@ def run_feddct(trainer, network, fl: FLConfig, *, use_kernel_agg: bool = False,
             if fl.target_accuracy and v_now >= fl.target_accuracy:
                 break
     run_span.end()
+    hist.meta.update(eng.run_meta())
     tel.summarize_into(hist.meta)
     return hist

@@ -16,10 +16,8 @@ segment/offset/shape/dtype views) cached once at construction.
   per-leaf host stacking, no dict lookups.
 * ``scatter(ids, flat_global)`` writes one global row into the merged
   clients' slots via ``buf.at[ids].set(...)`` under a jit that DONATES
-  the buffers (donation is applied on accelerator backends; XLA CPU
-  does not implement donation, so it is skipped there to avoid
-  warnings), so the store updates in place instead of copying N*P
-  floats per window.
+  the buffers on every backend, so the store updates in place instead
+  of copying N*P floats per window.
 * ``merge_scatter(ids, stacked_updates, coef, global_flat)`` is the
   fused tail of the async round step: staleness merge (global model as
   the implicit row 0, zero-coefficient rows masked to exact no-ops —
@@ -27,15 +25,15 @@ segment/offset/shape/dtype views) cached once at construction.
   flatten of the new global row + scatter, ONE jitted buffer-donating
   program per padded cohort-size bucket.  ``use_kernel=True``
   dispatches the merge through the folded Pallas fedagg kernel
-  (``fedagg_fold_pytree`` — interpret-mode on CPU, compiled on TPU),
+  (``fedagg_fold_pytree``),
   the SAME program the dict-of-pytrees reference's
   ``staleness_weighted_merge(use_kernel=True)`` runs, so kernel-path
   histories stay bit-identical between the two snapshot paths.
 
 Donation contract: the store owns its buffers.  Callers must NOT hold
 references into ``store.buffer``/``store.int_buffer`` across
-``scatter``/``merge_scatter`` calls — on donating backends the old
-buffer is invalidated in place.  ``gather``/``gather_one`` return
+``scatter``/``merge_scatter`` calls — the old buffer is invalidated
+in place.  ``gather``/``gather_one`` return
 fresh arrays and are always safe.
 
 Sharding: pass a 1-D client mesh to shard the row axis across devices
@@ -63,11 +61,10 @@ inside ``gather``/``gather_one`` as jitted programs per cohort bucket
 never materializes an f32 ``(N, P)`` buffer.  The quantize and
 dequantize math each live in ONE standalone compiled program shared by
 every residency layout (the donated row writes are separate programs):
-``dq = q*scale + zp`` is FMA-contractible, and XLA contracts
-differently per compilation unit, so fusing it into buffer-shaped
-programs would break cross-layout bit-identity — the PR 5
-merge-dispatch lesson applied to quantization.  Server-side **error-feedback accumulators** (on by
-default) keep each client's quantization residual ``x - dq(q(x))`` in
+fusing them into buffer-shaped programs would let XLA re-fuse their
+float math per compilation unit and break cross-layout bit-identity,
+as a fused merge once did.  Server-side **error-feedback accumulators**
+(on by default) keep each client's quantization residual ``x - dq(q(x))`` in
 sparse host memory — it models state a real deployment keeps at the
 client, so it is NOT counted as store bytes — and add it back before
 the next quantization of that client's row, making the stored
@@ -90,7 +87,7 @@ import numpy as np
 
 from repro.core.aggregation import _merge_folded_jnp
 from repro.kernels.ops import (dequantize_rows, dequantize_segment,
-                               fedagg_fold_pytree, on_cpu, quantize_rows,
+                               fedagg_fold_pytree, quantize_rows,
                                tree_spec)
 from repro.obs import telemetry as obs
 
@@ -200,11 +197,11 @@ def _from_quant_rows(qrows, mrows, irows, lead, treedef, entries, fsegs):
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(treedef, entries, donate: bool, fsegs=None):
+def _programs(treedef, entries, fsegs=None):
     """Jitted store programs, cached per (tree structure, segment
-    layout, donation mode, quantization layout) so every store over the
-    same model family shares compiled code — a fresh store per run
-    costs zero recompiles.
+    layout, quantization layout) so every store over the same model
+    family shares compiled code — a fresh store per run costs zero
+    recompiles.
 
     Every program takes the store's row-segment buffers as ONE tuple
     ``bufs``: ``(fbuf, ibuf)`` for the f32 store, or ``(qbuf int8,
@@ -224,14 +221,16 @@ def _programs(treedef, entries, donate: bool, fsegs=None):
 
     def quantize_impl(frow, ef):
         # One (Pf,) row + (K, Pf) per-client error-feedback residuals
-        # -> per-client int8 rows, scale/zp meta, and the NEXT
-        # residuals x - dq(q(x)).  Every reduction inside is a
-        # per-segment min/max (order-independent), so the produced
-        # bits cannot depend on K or on which program traced this.
+        # -> per-client int8 rows, scale/zp meta, the quantized inputs
+        # x and their dequantized rows dq(q(x)).  Every reduction
+        # inside is a per-segment min/max (order-independent), so the
+        # produced bits cannot depend on K or on which program traced
+        # this.  The residual x - dq(q(x)) is taken on host: traced,
+        # x - (q + snap) * scale is an a - b*c that XLA may contract
+        # into one FMA.
         x = frow[None, :] + ef
         qrows, mrows = quantize_rows(x, fsegs)
-        new_ef = x - dequantize_rows(qrows, mrows, fsegs)
-        return qrows, mrows, new_ef
+        return qrows, mrows, x, dequantize_rows(qrows, mrows, fsegs)
 
     def gather_impl(bufs, ids):
         # f32 stores only; quantized stores gather via read_rows ->
@@ -293,14 +292,14 @@ def _programs(treedef, entries, donate: bool, fsegs=None):
     def init_impl(params, rows):
         frow, irow = flatten_impl(params)
         if quant:
-            qrow, mrow, _ = quantize_impl(
+            qrow, mrow, _, _ = quantize_impl(
                 frow, jnp.zeros((1,) + frow.shape, jnp.float32))
             return (jnp.tile(qrow, (rows, 1)), jnp.tile(mrow, (rows, 1)),
                     jnp.tile(irow[None], (rows, 1)))
         return (jnp.tile(frow[None], (rows, 1)),
                 jnp.tile(irow[None], (rows, 1)))
 
-    dk = dict(donate_argnums=(0,)) if donate else {}
+    dk = dict(donate_argnums=(0,))
     return SimpleNamespace(
         flatten=jax.jit(flatten_impl),
         unflatten=jax.jit(unflatten_impl),
@@ -359,13 +358,10 @@ class ClientStateStore:
         # dense: every client's authoritative row lives on device.  The
         # tiered subclass overrides this tag ("tiered-host"/"tiered-disk").
         self.residency = "dense"
-        # XLA CPU does not implement buffer donation — donating there
-        # only emits warnings.  Donate on real accelerator backends.
-        self._donate = jax.default_backend() != "cpu"
-        obs.TEL.inc("store.donation_active" if self._donate
-                    else "store.donation_skipped")
-        self._fns = _programs(treedef, self.entries, self._donate,
-                              self._fsegs)
+        # the row writes donate their buffers on every backend, so the
+        # CPU tests run the same programs as the chip
+        obs.TEL.inc("store.donation_active")
+        self._fns = _programs(treedef, self.entries, self._fsegs)
         bufs = self._fns.init(template_params, self.rows)
         if self.mesh is not None:
             from jax.sharding import NamedSharding
@@ -415,15 +411,16 @@ class ClientStateStore:
                 out[j] = r
         return jnp.asarray(out)
 
-    def _ef_update(self, ids, new_ef):
-        """Store the (K, Pf) residuals the quantizing scatter returned.
-        Duplicate ids carried identical inputs, so last-write-wins is
-        exact."""
+    def _ef_update(self, ids, x, dq):
+        """Store the (K, Pf) residuals ``x - dq`` of the quantized
+        inputs ``x`` and their dequantized rows ``dq``, subtracted here
+        in numpy (one rounding, no FMA).  Duplicate ids carried
+        identical inputs, so last-write-wins is exact."""
         if not self.error_feedback:
             return
-        arr = np.asarray(new_ef, np.float32)
+        arr = np.asarray(x, np.float32) - np.asarray(dq, np.float32)
         for j, c in enumerate(ids):
-            self._ef[int(c)] = np.array(arr[j])
+            self._ef[int(c)] = arr[j]
 
     def ef_residual(self, client_id: int):
         """One client's current (Pf,) quantization residual, or None if
@@ -492,11 +489,10 @@ class ClientStateStore:
 
         Quantized stores dequantize through the ``from_rows`` program
         for EVERY layout (dense, tiered hot, tiered mixed) — one
-        compilation unit producing the f32 view, so gathered bits
-        cannot depend on residency (``dq = q*scale + zp`` is
-        FMA-contractible, and XLA may contract differently per
-        compilation unit — the PR 5 merge-dispatch lesson applied to
-        dequantization).
+        compilation unit producing the f32 view (``dq = (q + snap) *
+        scale``), so gathered bits cannot depend on residency (XLA may
+        fuse float math differently per compilation unit, as it does
+        for the merge).
         """
         idl = self._ids(ids)
         if self.quant_bits == 8:
@@ -516,9 +512,8 @@ class ClientStateStore:
         write would let XLA contract the dequantize FMA differently per
         buffer height, and the residuals (hence every later write)
         would diverge across residency layouts."""
-        qrows, mrows, new_ef = self._fns.quantize(frow,
-                                                  self._ef_block(ids))
-        self._ef_update(ids, new_ef)
+        qrows, mrows, x, dq = self._fns.quantize(frow, self._ef_block(ids))
+        self._ef_update(ids, x, dq)
         return qrows, mrows
 
     def scatter(self, ids: Sequence[int], flat_global):
@@ -561,9 +556,9 @@ class ClientStateStore:
         row 0 first) — zero entries (masked stragglers / padded rows)
         contribute exactly nothing.  ``params``: the current global
         model pytree.  ``use_kernel=True`` dispatches the merge through
-        the folded Pallas fedagg kernel (interpret-mode on CPU,
-        compiled on TPU) — the same ``fedagg_fold_pytree`` program the
-        dict path's ``staleness_weighted_merge(use_kernel=True)`` runs.
+        the folded Pallas fedagg kernel — the same ``fedagg_fold_pytree``
+        program the dict path's ``staleness_weighted_merge(use_kernel=
+        True)`` runs.
         Returns ``(new_params, new_global_flat)``.
 
         The merge ALWAYS dispatches the standalone jitted program the
@@ -578,12 +573,19 @@ class ClientStateStore:
         construction.
         """
         tel = obs.TEL
-        coef = jnp.asarray(np.asarray(coef, np.float32))  # fedlint: disable=FED002 -- coef is the host numpy staleness-coefficient vector, packing not a device readback
+        host_coef = np.asarray(coef, np.float32)  # fedlint: disable=FED002 -- coef is the host numpy staleness-coefficient vector, packing not a device readback
+        coef = jnp.asarray(host_coef)
         with tel.span("store.merge", rows=len(ids), kernel=use_kernel):
-            if use_kernel:
-                interp = on_cpu() if interpret is None else bool(interpret)
+            if use_kernel and self.mesh is not None:
+                # XLA cannot partition a Pallas kernel over the
+                # row-sharded cohort: reduce per shard + one psum
+                from repro.distributed.aggregate import sharded_fold_merge
+                new_params = sharded_fold_merge(
+                    self.mesh, params, stacked_updates, host_coef,
+                    use_kernel=True, interpret=interpret)
+            elif use_kernel:
                 new_params = fedagg_fold_pytree(params, stacked_updates,
-                                                coef, interpret=interp)
+                                                coef, interpret=interpret)
             else:
                 new_params = _merge_folded_jnp(params, stacked_updates,
                                                coef)

@@ -10,8 +10,7 @@ all-reduce plus one scalar.
 
 ``use_kernel=True`` dispatches each shard's partial sum through the
 Pallas ``fedagg_partial`` kernel instead of the jnp reduction — the
-per-shard fedagg dispatch the ROADMAP names (interpret-mode on CPU,
-compiled on TPU); the psum combine and the normalization are
+per-shard fedagg dispatch; the psum combine and the normalization are
 unchanged, so the masking semantics are identical.
 
 Numerics: identical masking semantics to the reference (rows with
@@ -33,13 +32,13 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.aggregation import staleness_merge_coefficients
 from repro.distributed.plan import ClientShardingPlan
 from repro.kernels.fedagg import fedagg_partial
-from repro.kernels.ops import flatten_updates, on_cpu, unflatten_result
+from repro.kernels.ops import (flatten_updates, resolve_interpret,
+                               unflatten_result)
 
 # (mesh, kernel dispatch) -> jitted shard_map reduction (meshes hash by
 # device assignment, so one compiled program per distinct client mesh
@@ -50,10 +49,10 @@ _MERGE_CACHE: Dict[tuple, object] = {}
 
 def _resolve_kernel(use_kernel, interpret):
     """Normalize the dispatch key: the jnp path ignores ``interpret``;
-    the kernel path defaults it to interpret-mode on CPU."""
+    the kernel path resolves it (``resolve_interpret``)."""
     if not use_kernel:
         return False, None
-    return True, (on_cpu() if interpret is None else bool(interpret))
+    return True, resolve_interpret(interpret)
 
 
 def _agg_fn(mesh, use_kernel: bool, interpret):
@@ -79,10 +78,10 @@ def _agg_fn(mesh, use_kernel: bool, interpret):
             den = jax.lax.psum(eff.sum(), axis)         # scalar
             return num / jnp.maximum(den, 1e-30), den
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             partial_reduce, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis)), out_specs=(P(), P()),
-            check_rep=False))
+            check_vma=False))
         _AGG_CACHE[key] = fn
     return fn
 
@@ -144,10 +143,10 @@ def _merge_fn(mesh, use_kernel: bool, interpret):
                 local = c @ masked
             return jax.lax.psum(local, axis)            # (P,)
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             partial_merge, mesh=mesh,
             in_specs=(P(axis), P(axis)), out_specs=P(),
-            check_rep=False))
+            check_vma=False))
         _MERGE_CACHE[key] = fn
     return fn
 
@@ -173,7 +172,20 @@ def sharded_staleness_merge(mesh, global_params, stacked, alphas, *,
     (masked stragglers) contribute exactly nothing.  ``use_kernel``
     dispatches each shard's partial sum through the Pallas
     ``fedagg_partial`` kernel."""
-    coef = staleness_merge_coefficients(alphas)
+    return sharded_fold_merge(mesh, global_params, stacked,
+                              staleness_merge_coefficients(alphas),
+                              use_kernel=use_kernel, interpret=interpret)
+
+
+def sharded_fold_merge(mesh, global_params, stacked, coef, *,
+                       use_kernel: bool = False, interpret=None):
+    """``sharded_staleness_merge`` from the (K+1,) telescoped
+    coefficients themselves (global row first; zero entries are
+    padding or masked stragglers).  The row-sharded client-state
+    store's kernel merge runs here: a Mosaic kernel cannot be
+    partitioned by XLA, so on a multi-device mesh it must run per
+    shard inside ``shard_map``."""
+    coef = np.asarray(coef, np.float64)
     # normalize host-side (the coefficients are host scalars already):
     # entries sum to 1 up to fp, mirroring the reference's in-program
     # normalization within reassociation tolerance.

@@ -18,9 +18,9 @@ unmodified.
 
 Pallas kernel aggregation (``use_kernel_agg``) dispatches each shard's
 partial sum through the ``fedagg_partial`` kernel inside the psum
-reduction (``repro.distributed.aggregate`` — interpret-mode on CPU,
-compiled on TPU); the combine and normalization are unchanged, so the
-flag changes how a shard reduces its own rows, not the semantics.
+reduction (``repro.distributed.aggregate``); the combine and
+normalization are unchanged, so the flag changes how a shard reduces
+its own rows, not the semantics.
 
 Single-device note: ``make_engine(..., mesh=<1-device mesh>)``
 deliberately returns the plain ``BatchedClientEngine`` — the
@@ -34,7 +34,6 @@ import inspect
 from typing import Callable, Dict, Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import BatchedClientEngine
@@ -68,8 +67,9 @@ def shard_cohort_train(mesh, train_fn: Callable, *,
     def _build(nargs: int):
         in_specs = tuple([P()] * replicated
                          + [P(axis)] * (nargs - replicated))
-        return jax.jit(shard_map(train_fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=P(axis), check_rep=False))
+        return jax.jit(jax.shard_map(train_fn, mesh=mesh,
+                                     in_specs=in_specs, out_specs=P(axis),
+                                     check_vma=False))
 
     def run(*args):
         sharded_args = args[replicated:]

@@ -27,13 +27,15 @@ def make_client_mesh(clients: Optional[int] = None, *,
                      devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
     """1-D ``("clients",)`` mesh over the first ``clients`` devices.
 
-    ``clients=None`` spans every available device; a request larger
-    than the device count is clamped (mirroring ``make_host_mesh``), so
-    ``--mesh-clients 8`` degrades gracefully on a single-device box.
+    ``clients=None`` spans every available device; a request for more
+    devices than exist raises, so ``--mesh-clients 4`` on a one-chip
+    machine cannot quietly run the single-device engine.
     """
     devs = list(devices if devices is not None else jax.devices())
     n = len(devs) if clients is None else int(clients)
     if n < 1:
         raise ValueError(f"client mesh needs at least one device, got {n}")
-    n = min(n, len(devs))
+    if n > len(devs):
+        raise ValueError(f"client mesh of {n} devices requested, but only "
+                         f"{len(devs)} exist")
     return jax.sharding.Mesh(np.asarray(devs[:n]), (CLIENT_AXIS,))

@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True``;
-on TPU they compile natively.  ``gqa_flash_attention`` adapts the model
-zoo's (B,S,H,D)/(B,T,Hkv,D) layout to the kernel's folded-head layout.
+``interpret=None`` resolves through ``resolve_interpret``: the Pallas
+interpreter on a CPU backend (the test suite), the compiled kernel on
+every other backend — a chip run never falls back to the interpreter.
+``gqa_flash_attention`` adapts the model zoo's (B,S,H,D)/(B,T,Hkv,D)
+layout to the kernel's folded-head layout.
 
 ``fedagg_pytree`` is the pytree-native server aggregation hot path: the
 stacked client-update pytree is flattened ONCE into a single (N, P)
@@ -24,14 +26,18 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssm_scan import ssm_scan
 
 
-def on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def resolve_interpret(interpret=None) -> bool:
+    """The caller's explicit choice, else interpret mode exactly when
+    the default backend is the CPU."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
 
 
 def gqa_flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
                         block_q=128, block_k=128, interpret=None):
     """q (B,S,H,D); k/v (B,T,Hkv,D) -> (B,S,H,D)."""
-    interpret = on_cpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     b, s, h, d = q.shape
     hkv = k.shape[2]
     rep = h // hkv
@@ -46,14 +52,14 @@ def gqa_flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 
 def ssm_scan_op(x, dt, b_in, c_out, a_log, *, chunk=128, block_d=256,
                 interpret=None):
-    interpret = on_cpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return ssm_scan(x, dt, b_in, c_out, a_log, chunk=chunk, block_d=block_d,
                     interpret=interpret)
 
 
-def fedagg_op(updates, weights, *, alphas=None, block_p=16384,
+def fedagg_op(updates, weights, *, alphas=None, block_p=None,
               interpret=None):
-    interpret = on_cpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return fedagg(updates, weights, alphas=alphas, block_p=block_p,
                   interpret=interpret)
 
@@ -131,7 +137,7 @@ def tree_spec(tree):
     return treedef, spec, total
 
 
-def fedagg_pytree(stacked_updates, weights, *, alphas=None, block_p=16384,
+def fedagg_pytree(stacked_updates, weights, *, alphas=None, block_p=None,
                   interpret=None):
     """Weighted-average a pytree whose leaves are stacked (N, ...).
 
@@ -141,7 +147,7 @@ def fedagg_pytree(stacked_updates, weights, *, alphas=None, block_p=16384,
     adds per-row staleness coefficients (effective weight
     ``w_c * alpha_c``); a zero-alpha row is masked like a zero weight.
     """
-    interpret = on_cpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     buf, treedef, spec = flatten_updates(stacked_updates)
     flat = fedagg(buf, weights, alphas=alphas, block_p=block_p,
                   interpret=interpret)
@@ -247,20 +253,20 @@ def dequantize_segment(qrows, meta, segs, j):
     return (q + meta[..., len(segs) + j, None]) * meta[..., j, None]
 
 
-def fedagg_fold_op(updates, g, coef, *, block_p=16384, interpret=None):
-    interpret = on_cpu() if interpret is None else interpret
+def fedagg_fold_op(updates, g, coef, *, block_p=None, interpret=None):
+    interpret = resolve_interpret(interpret)
     return fedagg_fold(updates, g, coef, block_p=block_p,
                        interpret=interpret)
 
 
-def fedagg_partial_op(updates, coef, *, block_p=16384, interpret=None):
-    interpret = on_cpu() if interpret is None else interpret
+def fedagg_partial_op(updates, coef, *, block_p=None, interpret=None):
+    interpret = resolve_interpret(interpret)
     return fedagg_partial(updates, coef, block_p=block_p,
                           interpret=interpret)
 
 
 def fedagg_fold_pytree(global_params, stacked_updates, coef, *,
-                       block_p=16384, interpret=None):
+                       block_p=None, interpret=None):
     """Folded staleness window merge over pytrees: ONE kernel pass on
     the flattened (K, P) client-row buffer with the global model as the
     IMPLICIT row 0 (its (P,) row rides in directly — no (K+1, ...)
@@ -273,7 +279,7 @@ def fedagg_fold_pytree(global_params, stacked_updates, coef, *,
     ``staleness_merge_coefficients`` vector (global first); padded /
     masked rows carry coefficient 0 and contribute exactly nothing.
     """
-    interpret = on_cpu() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     buf, treedef, spec = flatten_updates(stacked_updates)
     g_flat = flatten_params_row(global_params)
     flat = fedagg_fold(buf, g_flat, coef, block_p=block_p,

@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, y_ref, h_ref, *,
             chunk: int):
@@ -74,7 +70,7 @@ def ssm_scan(x, dt, b_in, c_out, a_log, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, q, bd), lambda b, j, t: (b, t, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, b_in, c_out, a_log)

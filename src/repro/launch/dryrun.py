@@ -32,7 +32,7 @@ from repro.config import get_arch
 from repro.config.base import INPUT_SHAPES, InputShape, ModelConfig, TrainConfig
 from repro.launch import steps as steps_lib
 from repro.launch.mesh import make_production_mesh
-from repro.roofline import analyze_hlo, roofline_terms
+from repro.roofline import TPU_V5E, analyze_hlo, roofline_terms
 from repro.sharding import (batch_specs, decode_state_specs, named_shardings,
                             param_specs)
 from repro.sharding.hints import set_mesh
@@ -149,7 +149,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     bytes_pc = rec["xla_cost"]["bytes_accessed"] * scan_ratio
     terms = roofline_terms(hlo_flops=flops_pc, hbm_bytes=bytes_pc,
                            collective_bytes=hlo["collective_wire_bytes"],
-                           chips=1)
+                           chips=1, hw=TPU_V5E)
     mf = steps_lib.model_flops(cfg, shape)
     terms["model_flops_global"] = mf
     terms["hlo_flops_global"] = flops_pc * n_chips

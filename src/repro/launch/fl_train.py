@@ -17,9 +17,11 @@ from repro.config.base import FLConfig
 from repro.core import run_method
 from repro.fl.client import build_fl_clients
 from repro.fl.network import WirelessNetwork
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--method", default="feddct",

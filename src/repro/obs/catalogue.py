@@ -40,7 +40,7 @@ COUNTERS = frozenset({
     "drain.count", "drain.deadline", "drain.budget", "drain.sequential",
     "drain.queue_drained", "drain.queue_empty",
     "stragglers.carried", "stragglers.dropped",
-    "store.donation_active", "store.donation_skipped",
+    "store.donation_active",
     "jax.compiles",
     # flstats labeled bases (tier/client labels stripped before check)
     "fl.tier.selected", "fl.tier.participate", "fl.tier.timeout",

@@ -262,12 +262,26 @@ class HWSpec:
     ici_bw: float              # bytes/s per link
 
 
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e").
 TPU_V5E = HWSpec("tpu-v5e", peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9)
+
+# jax ``device_kind`` -> peaks.  A device missing here has no peaks:
+# ``hw_spec`` raises rather than borrowing another chip's numbers.
+PEAKS: Dict[str, HWSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def hw_spec(device_kind: str) -> HWSpec:
+    """Peaks of the chip jax reports as ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak table for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
 
 
 def roofline_terms(*, hlo_flops: float, hbm_bytes: float,
                    collective_bytes: float, chips: int,
-                   hw: HWSpec = TPU_V5E) -> Dict[str, float]:
+                   hw: HWSpec) -> Dict[str, float]:
     compute = hlo_flops / (chips * hw.peak_flops)
     memory = hbm_bytes / (chips * hw.hbm_bw)
     collective = collective_bytes / (chips * hw.ici_bw)

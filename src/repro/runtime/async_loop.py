@@ -377,6 +377,7 @@ class AsyncRunner:
             hist.meta["store_bytes_cold"] = bt["cold"]
             hist.meta["store_bytes_ef"] = bt["ef"]
         run_span.end()
+        hist.meta.update(eng.run_meta())
         tel.summarize_into(hist.meta)
         return hist
 
@@ -586,5 +587,6 @@ def run_feddct_async(trainer, network, fl: FLConfig, *,
         hist.meta["store_bytes_cold"] = bt["cold"]
         hist.meta["store_bytes_ef"] = bt["ef"]
     run_span.end()
+    hist.meta.update(eng.run_meta())
     tel.summarize_into(hist.meta)
     return hist

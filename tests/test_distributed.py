@@ -117,7 +117,9 @@ def test_make_client_mesh_spans_all_devices():
 
 def test_make_client_mesh_subset_and_clamp():
     assert int(make_client_mesh(1).size) == 1
-    assert int(make_client_mesh(10 ** 6).size) == N_DEV   # clamped
+    assert int(make_client_mesh(N_DEV).size) == N_DEV
+    with pytest.raises(ValueError, match="only"):   # never clamped
+        make_client_mesh(N_DEV + 1)
     with pytest.raises(ValueError):
         make_client_mesh(0)
 
@@ -537,6 +539,34 @@ def test_client_state_store_sharded_matches_plain():
     ps, _ = shard.merge_scatter(ids, stacked, coef, template)
     _assert_tree_close(ps, pp)
     _assert_tree_close(shard.gather_one(4), plain.gather_one(4))
+
+
+@multi_device
+def test_client_state_store_sharded_kernel_merge_matches_plain():
+    """The row-sharded store's kernel merge reduces per shard inside
+    shard_map (a Pallas kernel cannot be partitioned by XLA on a
+    multi-chip mesh) and matches the single-device kernel merge."""
+    from repro.core.aggregation import staleness_merge_coefficients
+    from repro.core.state import ClientStateStore
+    mesh = make_client_mesh()
+    template = {"w": jnp.asarray(np.arange(300.0, dtype=np.float32)
+                                 .reshape(3, 100) / 300.0),
+                "b": jnp.zeros((7,), jnp.float32)}
+    plain = ClientStateStore(template, 12)
+    shard = ClientStateStore(template, 12, mesh=mesh)
+    stacked = jax.tree_util.tree_map(
+        lambda l: jnp.stack([l * (1.0 + 0.1 * i) + i for i in range(8)]),
+        template)
+    alphas = 0.6 * (np.arange(8, dtype=np.float64) + 1.0) ** -0.5
+    alphas[5] = 0.0
+    coef = staleness_merge_coefficients(alphas)
+    ids = list(range(8))
+    pp, _ = plain.merge_scatter(ids, stacked, coef, template,
+                                use_kernel=True)
+    ps, _ = shard.merge_scatter(ids, stacked, coef, template,
+                                use_kernel=True)
+    _assert_tree_close(ps, pp)
+    _assert_tree_close(shard.gather_one(2), plain.gather_one(2))
 
 
 @multi_device

@@ -7,6 +7,7 @@ import pytest
 
 from repro.kernels import (fedagg_fold_op, fedagg_op, fedagg_partial_op,
                            gqa_flash_attention, ssm_scan_op)
+from repro.kernels.fedagg import PANEL_BYTES, default_block_p
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import (fedagg_fold_ref, fedagg_partial_ref,
                                fedagg_ref, flash_attention_ref,
@@ -128,8 +129,8 @@ def test_ssm_scan_bf16():
 # fedagg
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,p,bp", [(3, 100, 64), (8, 4096, 1024),
-                                    (50, 999, 256), (1, 17, 64)])
+@pytest.mark.parametrize("n,p,bp", [(3, 300, 128), (8, 4096, 1024),
+                                    (50, 999, 256), (1, 17, 128)])
 def test_fedagg_shapes(n, p, bp):
     ks = jax.random.split(KEY, 2)
     u = jax.random.normal(ks[0], (n, p), jnp.float32)
@@ -144,7 +145,7 @@ def test_fedagg_shapes(n, p, bp):
 def test_fedagg_dtypes(dtype):
     u = (jnp.arange(12.0).reshape(3, 4) / 10).astype(dtype)
     w = jnp.asarray([1.0, 1.0, 2.0])
-    out = fedagg_op(u, w, block_p=4, interpret=True)
+    out = fedagg_op(u, w, block_p=128, interpret=True)
     ref = fedagg_ref(u, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
@@ -154,8 +155,8 @@ def test_fedagg_dtypes(dtype):
 # fedagg_fold (implicit global row 0) and fedagg_partial (per-shard sum)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("k,p,bp", [(3, 100, 64), (8, 999, 256),
-                                    (1, 17, 64)])
+@pytest.mark.parametrize("k,p,bp", [(3, 300, 128), (8, 999, 256),
+                                    (1, 17, 128)])
 def test_fedagg_fold_shapes(k, p, bp):
     ks = jax.random.split(KEY, 3)
     u = jax.random.normal(ks[0], (k, p), jnp.float32)
@@ -198,7 +199,7 @@ def test_fedagg_fold_all_zero_coef_gives_zeros():
     np.testing.assert_allclose(np.asarray(out), 0.0, atol=0)
 
 
-@pytest.mark.parametrize("n,p,bp", [(4, 100, 64), (7, 513, 128)])
+@pytest.mark.parametrize("n,p,bp", [(4, 300, 128), (7, 513, 128)])
 def test_fedagg_partial_shapes(n, p, bp):
     ks = jax.random.split(KEY, 2)
     u = jax.random.normal(ks[0], (n, p), jnp.float32)
@@ -215,3 +216,19 @@ def test_fedagg_partial_is_unnormalized_and_masked():
     c = jnp.asarray([0.5, 0.0, 2.0], jnp.float32)
     out = fedagg_partial_op(u, c, interpret=True)
     np.testing.assert_allclose(np.asarray(out), [3.0, 4.0], rtol=1e-6)
+
+
+def test_fedagg_block_p_must_be_lane_aligned():
+    u = jnp.ones((3, 300), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        fedagg_partial_op(u, jnp.ones(3), block_p=64, interpret=True)
+
+
+@pytest.mark.parametrize("k", [1, 16, 64, 256, 4096])
+def test_fedagg_default_block_p_fits_vmem_panel(k):
+    """The default panel is lane-aligned and its (K, block_p) f32 input
+    stays within the budget that leaves room for double buffering in
+    v5e's 16 MiB scoped VMEM."""
+    bp = default_block_p(k)
+    assert bp % 128 == 0
+    assert max(k, 8) * bp * 4 <= PANEL_BYTES or bp == 128

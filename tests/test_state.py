@@ -224,15 +224,17 @@ def test_merge_scatter_masks_nonfinite_zero_coef_rows():
 
 
 def test_repeated_inplace_updates_no_use_after_donate():
-    """scatter/merge_scatter donate the buffer: the store must rebind
-    and keep serving gathers across many cycles (donation is active on
-    accelerator backends; this exercises the rebind discipline)."""
+    """scatter/merge_scatter donate the buffer on every backend: the
+    old buffer is deleted, and the store must rebind and keep serving
+    gathers across many cycles."""
     g = _template(14)
     store = ClientStateStore(g, 6)
     params = g
     for it in range(5):
         t = _template(40 + it)
+        old = store.bufs
         store.scatter_params([it % 6], t)
+        assert all(b.is_deleted() for b in old)
         stacked = _stack([t, _template(50 + it)])
         coef = staleness_merge_coefficients([0.5, 0.25])
         params, _ = store.merge_scatter([it % 6, (it + 1) % 6], stacked,

@@ -10,7 +10,7 @@ from repro.config.base import INPUT_SHAPES
 from repro.launch.steps import (abstract_decode_state, abstract_opt_state,
                                 abstract_params, input_specs, model_flops,
                                 swa_window_for)
-from repro.roofline import analyze_hlo, roofline_terms
+from repro.roofline import TPU_V5E, analyze_hlo, hw_spec, roofline_terms
 from repro.roofline.analysis import _shape_bytes, _trip_count
 
 
@@ -103,9 +103,15 @@ def test_analyzer_on_scanned_matmul():
 
 def test_roofline_terms_dominance():
     t = roofline_terms(hlo_flops=197e12, hbm_bytes=0, collective_bytes=0,
-                       chips=1)
+                       chips=1, hw=TPU_V5E)
     assert t["dominant"] == "compute_s"
     assert t["compute_s"] == pytest.approx(1.0)
     t2 = roofline_terms(hlo_flops=0, hbm_bytes=819e9, collective_bytes=1e12,
-                        chips=1)
+                        chips=1, hw=TPU_V5E)
     assert t2["dominant"] == "collective_s"
+
+
+def test_hw_spec_known_kind_and_unknown_raises():
+    assert hw_spec("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="no peak table"):
+        hw_spec("cpu")
