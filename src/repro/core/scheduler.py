@@ -81,68 +81,76 @@ def run_feddct(trainer, network, fl: FLConfig, *, use_kernel_agg: bool = False,
 
     for rnd in range(1, fl.rounds + 1):
         tel.set_virtual_time(clock)
-        # ---- rejoin clients whose re-evaluation completed --------------
-        for c in [c for c, (tr, _) in eval_lane.items() if tr <= clock]:
-            at[c] = eval_lane.pop(c)[1]
+        with tel.span("round", rnd=rnd) as round_span:
+            # ---- rejoin clients whose re-evaluation completed ----------
+            for c in [c for c, (tr, _) in eval_lane.items() if tr <= clock]:
+                at[c] = eval_lane.pop(c)[1]
 
-        avail_at = {c: v for c, v in at.items() if c not in eval_lane}
-        sel_span = tel.span("round.select", avail=len(avail_at)).start()
-        tiers = tiering(avail_at, m)
-        if not tiers:
-            sel_span.end()
-            break
-
-        selected, d_max, t_ptr = cstt(
-            t_ptr, v_prev, v_curr, tiers, avail_at, ct, fl.tau, fl.beta,
-            fl.omega, rng)
-        flstats.record_tiering(tiers, thresholds=d_max,
-                               population=fl.n_clients)
-        flstats.record_selection(selected)
-
-        # ---- virtual delays decide survivors BEFORE any training ------
-        survivors: List[int] = []
-        times_per_tier: Dict[int, List[float]] = {}
-        n_straggle = 0
-        sts = network.delays([c for c, _ in selected], rnd)
-        for (c, k), st in zip(selected, sts):
-            times_per_tier.setdefault(k, []).append(min(st, d_max[k]))
-            flstats.record_response(k + 1, float(st), d_max[k],
-                                    timed_out=st >= d_max[k])
-            if st >= d_max[k]:
-                # straggler: drop update, enter evaluation lane
-                n_straggle += 1
-                flstats.record_straggler("dropped", tier=k + 1)
-                new_at, spent = evaluate_client(network, c, rnd, fl.kappa,
-                                                fl.omega)
-                eval_lane[c] = (clock + spent, new_at)
-                continue
-            survivors.append(c)
-            at[c] = update_avg_time(at[c], ct[c], st)
-            ct[c] += 1
-        sel_span.end()
-        if n_straggle:
-            tel.inc("stragglers.dropped", n_straggle)
-
-        # ---- one batched device program for the whole cohort ----------
-        params = eng.train_round(params, survivors, rnd)
-
-        # Eq. 5/6 round duration
-        d_round = 0.0
-        for k, ts_k in times_per_tier.items():
-            d_round = max(d_round, min(max(ts_k), d_max[k], fl.omega))
-        clock += d_round
-
-        if rnd % eval_every == 0:
-            with tel.span("eval"):
-                v_now = trainer.evaluate(params)
-            hist.record(time=clock, rnd=rnd, acc=v_now, tier=t_ptr,
-                        n_selected=len(selected), n_stragglers=n_straggle)
-            v_prev, v_curr = v_curr, v_now
-            if verbose:
-                print(f"[feddct] r={rnd:4d} t={clock:9.1f}s tier={t_ptr} "
-                      f"acc={v_now:.4f} sel={len(selected)} str={n_straggle}")
-            if fl.target_accuracy and v_now >= fl.target_accuracy:
+            avail_at = {c: v for c, v in at.items() if c not in eval_lane}
+            sel_span = tel.span("round.select", avail=len(avail_at)).start()
+            tiers = tiering(avail_at, m)
+            if not tiers:
+                sel_span.end()
                 break
+
+            selected, d_max, t_ptr = cstt(
+                t_ptr, v_prev, v_curr, tiers, avail_at, ct, fl.tau, fl.beta,
+                fl.omega, rng)
+            flstats.record_tiering(tiers, thresholds=d_max,
+                                   population=fl.n_clients)
+            flstats.record_selection(selected)
+
+            # ---- virtual delays decide survivors BEFORE any training --
+            survivors: List[int] = []
+            times_per_tier: Dict[int, List[float]] = {}
+            n_straggle = 0
+            sts = network.delays([c for c, _ in selected], rnd)
+            for (c, k), st in zip(selected, sts):
+                times_per_tier.setdefault(k, []).append(min(st, d_max[k]))
+                flstats.record_response(k + 1, float(st), d_max[k],
+                                        timed_out=st >= d_max[k])
+                if st >= d_max[k]:
+                    # straggler: drop update, enter evaluation lane
+                    n_straggle += 1
+                    flstats.record_straggler("dropped", tier=k + 1)
+                    new_at, spent = evaluate_client(network, c, rnd,
+                                                    fl.kappa, fl.omega)
+                    eval_lane[c] = (clock + spent, new_at)
+                    continue
+                survivors.append(c)
+                at[c] = update_avg_time(at[c], ct[c], st)
+                ct[c] += 1
+            sel_span.end()
+            if tel.enabled:
+                round_span.set(selected=len(selected),
+                               survivors=len(survivors))
+            if n_straggle:
+                tel.inc("stragglers.dropped", n_straggle)
+
+            # ---- one batched device program for the whole cohort ------
+            params = eng.train_round(params, survivors, rnd)
+
+            # Eq. 5/6 round duration
+            d_round = 0.0
+            for k, ts_k in times_per_tier.items():
+                d_round = max(d_round, min(max(ts_k), d_max[k],
+                                           fl.omega))
+            clock += d_round
+            tel.set_virtual_time(clock)     # the round span covers it
+
+            if rnd % eval_every == 0:
+                with tel.span("eval"):
+                    v_now = trainer.evaluate(params)
+                hist.record(time=clock, rnd=rnd, acc=v_now, tier=t_ptr,
+                            n_selected=len(selected),
+                            n_stragglers=n_straggle)
+                v_prev, v_curr = v_curr, v_now
+                if verbose:
+                    print(f"[feddct] r={rnd:4d} t={clock:9.1f}s "
+                          f"tier={t_ptr} acc={v_now:.4f} "
+                          f"sel={len(selected)} str={n_straggle}")
+                if fl.target_accuracy and v_now >= fl.target_accuracy:
+                    break
     run_span.end()
     hist.meta.update(eng.run_meta())
     tel.summarize_into(hist.meta)

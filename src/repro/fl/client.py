@@ -27,6 +27,7 @@ from repro.data.synthetic import make_image_dataset, make_token_dataset
 from repro.models.cnn import cnn_forward, cnn_loss, init_cnn
 from repro.models.transformer import forward as lm_forward
 from repro.models.transformer import init_model, lm_loss
+from repro.obs import telemetry as obs
 from repro.optim import make_optimizer
 
 
@@ -115,23 +116,31 @@ class CNNTrainer:
         (client, seed)-keyed batch stream once, bucket positions by
         stream shape (ragged partitions), run ``train_chunk(xs, ys,
         positions)`` per bucket, and reassemble chunk rows in input
-        order."""
-        data = {}                     # pad slots repeat (client, seed)
-        buckets: Dict[tuple, List[int]] = {}
-        for pos, key in enumerate(keys):
-            if key not in data:       # keys, so compute each stream once
-                data[key] = self._client_epoch_batches(*key)
-            buckets.setdefault(data[key][0].shape, []).append(pos)
-        chunks, order = [], []
-        for positions in buckets.values():
-            xs = jnp.asarray(np.stack([data[keys[p]][0]
-                                       for p in positions]))
-            ys = jnp.asarray(np.stack([data[keys[p]][1]
-                                       for p in positions]))
-            chunks.append(train_chunk(xs, ys, positions))
-            order.extend(positions)
+        order.  Spans: ``train.batches`` (streams and stacks, on the
+        host), ``train.h2d`` (the copy to the device), ``train.dispatch``
+        (the bucket programs)."""
+        tel = obs.TEL
+        with tel.span("train.batches") as span:
+            data = {}                 # pad slots repeat (client, seed)
+            buckets: Dict[tuple, List[int]] = {}
+            for pos, key in enumerate(keys):
+                if key not in data:   # keys, so compute each stream once
+                    data[key] = self._client_epoch_batches(*key)
+                buckets.setdefault(data[key][0].shape, []).append(pos)
+            host = [(np.stack([data[keys[p]][0] for p in positions]),
+                     np.stack([data[keys[p]][1] for p in positions]))
+                    for positions in buckets.values()]
+            if tel.enabled:
+                span.set(streams=len(data))
+        nbytes = sum(xs.nbytes + ys.nbytes for xs, ys in host)
+        with tel.span("train.h2d", bytes=nbytes):
+            dev = [(jnp.asarray(xs), jnp.asarray(ys)) for xs, ys in host]
+        with tel.span("train.dispatch"):
+            chunks = [train_chunk(xs, ys, positions) for (xs, ys), positions
+                      in zip(dev, buckets.values())]
         if len(chunks) == 1:          # common case: one shape bucket,
             return chunks[0]          # order already the input order
+        order = [p for positions in buckets.values() for p in positions]
         inv = np.argsort(np.asarray(order))
         return jax.tree_util.tree_map(
             lambda *leaves: jnp.concatenate(leaves, axis=0)[inv], *chunks)

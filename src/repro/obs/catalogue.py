@@ -20,7 +20,8 @@ from __future__ import annotations
 #: span names (see ROADMAP "Telemetry" for who records each)
 SPANS = frozenset({
     "run",
-    "round.select", "round.train", "round.aggregate",
+    "round", "round.select", "round.train", "round.aggregate",
+    "train.batches", "train.h2d", "train.dispatch",
     "window.stage", "window.gather", "window.train",
     "window.merge_scatter",
     "window.prefetch", "window.merge", "window.reschedule",

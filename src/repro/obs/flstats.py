@@ -56,6 +56,7 @@ hist    ``fl.cohort.update_norm``            per-cohort update L2 norm
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 from repro.obs import telemetry as obs
@@ -246,18 +247,32 @@ def record_client_updates(client_ids: Iterable[int]):
         _inc(tel, "fl.client.update", client=int(c))
 
 
+@functools.lru_cache(maxsize=None)
+def _update_norm_program():
+    """One jitted reduction: the L2 norm of the first ``n_rows`` rows of
+    every leaf (``n_rows`` traced, so one program per cohort bucket)."""
+    import jax
+    import jax.numpy as jnp
+
+    def norm(stacked, n_rows):
+        total = jnp.float32(0)
+        for leaf in jax.tree_util.tree_leaves(stacked):
+            rows = leaf.astype(jnp.float32).reshape(leaf.shape[0], -1)
+            keep = jnp.arange(leaf.shape[0]) < n_rows
+            total += jnp.sum(jnp.where(keep[:, None], rows * rows, 0))
+        return jnp.sqrt(total)
+
+    return jax.jit(norm)
+
+
 def record_update_norm(stacked, n_rows: int):
     """L2 norm of one drained cohort's stacked update rows (the first
     ``n_rows`` — the rest are pad duplicates).  Pure read of values the
-    run already produced; the device sync it forces only exists while
-    tracing."""
+    run already produced: one device reduction, kept as a device scalar
+    (no host sync) until ``Telemetry.summary`` reads it back."""
     tel = obs.TEL
     if not tel.enabled or stacked is None or n_rows <= 0:
         return
-    import jax
-    import jax.numpy as jnp
-    total = 0.0
-    for leaf in jax.tree_util.tree_leaves(stacked):
-        rows = leaf[:n_rows].astype(jnp.float32)
-        total += float(jnp.sum(rows * rows))
-    tel.observe("fl.cohort.update_norm", total ** 0.5)
+    import numpy as np
+    tel.observe("fl.cohort.update_norm",
+                _update_norm_program()(stacked, np.int32(n_rows)))

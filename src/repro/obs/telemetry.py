@@ -18,6 +18,10 @@ carry.  This module is the zero-overhead-when-disabled core:
   runners maintain via ``set_virtual_time`` — so a trace can show that
   a merge which took 2 ms of host time covered 40 virtual seconds of
   simulated network wait.
+* each recording span also opens a ``jax.profiler.TraceAnnotation``
+  of its name and start args, so while the JAX profiler runs the
+  program's spans land on the trace's host plane, on the same clock
+  as the device ops;
 * counters / gauges / histograms (``inc`` / ``gauge`` / ``observe``)
   feed the end-of-run aggregate (``summary`` /
   ``summarize_into(hist.meta)`` — the ``meta["telemetry"]`` block).
@@ -114,9 +118,13 @@ TEL = NOOP
 class Span:
     """One traced section: wall-clock + virtual-time interval with
     attached args.  Works as a context manager or via explicit
-    ``start()`` / ``end()`` (for loops that cannot re-indent)."""
+    ``start()`` / ``end()`` (for loops that cannot re-indent).
 
-    __slots__ = ("_tel", "name", "args", "t0", "vt0")
+    Each span also opens a ``jax.profiler.TraceAnnotation`` of its name
+    and start args, so it lands on the profiler's host plane on the
+    device trace's clock (a no-op unless the profiler is running)."""
+
+    __slots__ = ("_tel", "name", "args", "t0", "vt0", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, args: Dict):
         self._tel = tel
@@ -124,6 +132,7 @@ class Span:
         self.args = args
         self.t0 = 0.0
         self.vt0 = 0.0
+        self._ann = None
 
     def set(self, **args):
         self.args.update(args)
@@ -132,9 +141,15 @@ class Span:
     def start(self):
         self.t0 = perf_counter()
         self.vt0 = self._tel.vt
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         return self
 
     def end(self):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self._tel._record_span(self)
         return self
 
@@ -196,11 +211,26 @@ class Telemetry:
             self.inc("telemetry.dropped_gauge_points")
 
     def observe(self, name: str, value):
+        """``value`` may be a device scalar: it is kept as it is, with
+        no host sync, and read back in ``summary``."""
         vals = self.hists.setdefault(name, [])
         if len(vals) < MAX_HIST:
-            vals.append(float(value))
+            vals.append(value if hasattr(value, "block_until_ready")
+                        else float(value))
         else:
             self.inc("telemetry.dropped_hist_points")
+
+    def _read_back_hists(self):
+        """Replace every device scalar ``observe`` kept by its float,
+        in one batched read."""
+        lazy = [(vals, i) for vals in self.hists.values()
+                for i, v in enumerate(vals) if type(v) is not float]
+        if not lazy:
+            return
+        import jax
+        got = jax.device_get([vals[i] for vals, i in lazy])
+        for (vals, i), v in zip(lazy, got):
+            vals[i] = float(v)
 
     # -- aggregate summary ----------------------------------------------
     def summary(self) -> Dict:
@@ -216,6 +246,7 @@ class Telemetry:
             agg["total_vt"] += s["vt1"] - s["vt0"]
         for agg in spans.values():
             agg["mean_s"] = agg["total_s"] / agg["count"]
+        self._read_back_hists()
         hists = {}
         for name, vals in self.hists.items():
             import numpy as np

@@ -369,6 +369,29 @@ def test_flstats_disabled_is_inert():
     flstats.record_update_norm(None, 0)
 
 
+def test_update_norm_is_one_device_scalar_until_summary():
+    """While tracing, the cohort update norm stays a device scalar (no
+    host read per window) and ``summary`` reads it back as the L2 norm
+    of the first ``n_rows`` rows."""
+    import jax.numpy as jnp
+    import numpy as np
+    stacked = {"a": jnp.arange(12.0).reshape(4, 3),
+               "b": jnp.ones((4, 2, 2), jnp.bfloat16)}
+    with obs.tracing() as tel:
+        flstats.record_update_norm(stacked, 3)
+        flstats.record_update_norm(stacked, 4)
+        kept = tel.hists["fl.cohort.update_norm"]
+        assert [type(v) is float for v in kept] == [False, False]
+        assert all(v.shape == () for v in kept)
+        summary = tel.summary()
+    want = [np.sqrt(np.sum(np.arange(9.0) ** 2) + 12),
+            np.sqrt(np.sum(np.arange(12.0) ** 2) + 16)]
+    assert tel.hists["fl.cohort.update_norm"] == pytest.approx(want)
+    assert all(type(v) is float for v in tel.hists["fl.cohort.update_norm"])
+    assert summary["hists"]["fl.cohort.update_norm"]["max"] == pytest.approx(
+        want[1])
+
+
 def test_flstats_cardinality_cap(monkeypatch):
     monkeypatch.setattr(flstats, "MAX_LABELS_PER_METRIC", 2)
     with obs.tracing() as tel:
