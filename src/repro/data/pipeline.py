@@ -17,14 +17,21 @@ class ClientDataset:
         return len(self.y)
 
 
+def epoch_batch_rows(n: int, batch_size: int, epoch_seed: int
+                     ) -> np.ndarray:
+    """Sample positions of one local epoch's batches, (n_batches,
+    batch): a seeded shuffle of ``n`` samples cut into full batches
+    (drops the ragged tail like FedLab; fewer samples than one batch
+    give one short batch)."""
+    idx = np.random.default_rng(epoch_seed).permutation(n)
+    n_full = max(n // batch_size, 1)
+    return idx[:n_full * batch_size].reshape(n_full, -1)
+
+
 def client_batches(ds: ClientDataset, batch_size: int, epoch_seed: int
                    ) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
-    """One local epoch of shuffled batches (drops ragged tail like FedLab)."""
-    rng = np.random.default_rng(epoch_seed)
-    idx = rng.permutation(len(ds))
-    n_full = max(len(ds) // batch_size, 1)
-    for b in range(n_full):
-        sl = idx[b * batch_size:(b + 1) * batch_size]
+    """One local epoch of shuffled batches (``epoch_batch_rows``)."""
+    for sl in epoch_batch_rows(len(ds), batch_size, epoch_seed):
         if len(sl) == 0:
             break
         yield ds.x[sl], ds.y[sl]

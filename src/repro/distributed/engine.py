@@ -34,6 +34,7 @@ import inspect
 from typing import Callable, Dict, Optional
 
 import jax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engine import BatchedClientEngine
@@ -85,6 +86,10 @@ def shard_cohort_train(mesh, train_fn: Callable, *,
             fn = jitted[len(args)] = _build(len(args))
         return plan.unpad(fn(*args[:replicated], *padded))
 
+    # the replicated args' placement: a caller whose replicated arg is the
+    # same on every call (a trainer's sample table) puts it there once,
+    # so that no call copies it over the mesh again
+    run.replicated_sharding = NamedSharding(mesh, P())
     return run
 
 

@@ -22,7 +22,8 @@ import numpy as np
 
 from repro.config.base import FLConfig, ModelConfig
 from repro.data.partition import primary_class_partition
-from repro.data.pipeline import ClientDataset, client_batches
+from repro.data.pipeline import (ClientDataset, client_batches,
+                                 epoch_batch_rows)
 from repro.data.synthetic import make_image_dataset, make_token_dataset
 from repro.models.cnn import cnn_forward, cnn_loss, init_cnn
 from repro.models.transformer import forward as lm_forward
@@ -42,11 +43,25 @@ class CNNTrainer:
         self.clients: List[ClientDataset] = [
             ClientDataset(data["x_train"][p], data["y_train"][p])
             for p in parts]
+        # The training split stays on the device as a sample table, one
+        # flat row per sample and the labels beside it; a round sends
+        # only table rows (``parts`` maps a client's samples to rows).
+        x_train = data["x_train"]
+        self._parts = parts
+        self._sample_shape = x_train.shape[1:]
+        table = (jnp.asarray(x_train.reshape(len(x_train), -1)),
+                 jnp.asarray(data["y_train"]))
+        # by placement: None is the default device; a client-sharded
+        # runner's replicated sharding gets a copy of its own
+        self._tables: Dict[object, tuple] = {None: table}
+        obs.TEL.gauge("train.resident_bytes",
+                      table[0].nbytes + table[1].nbytes)
         self.x_test = jnp.asarray(data["x_test"])
         self.y_test = jnp.asarray(data["y_test"])
         self.opt = make_optimizer(fl.optimizer)
         self._step = jax.jit(self._step_impl, static_argnames=("im2col",))
         self._eval = jax.jit(self._eval_impl)
+        self._gather = jax.jit(self._gather_batches)
         self._batch_train = jax.jit(self._batch_train_impl)
         self._batch_train_multi = jax.jit(self._batch_train_multi_impl)
 
@@ -78,17 +93,36 @@ class CNNTrainer:
         return params, len(ds)
 
     # -- batched multi-client path (engine hot path) --------------------
-    def _client_epoch_batches(self, client_id: int, rnd_seed: int):
-        """All local-training batches for one client, identical stream to
-        the looped ``local_train`` (same seeds, same order)."""
-        ds = self.clients[client_id]
-        xs, ys = [], []
-        for ep in range(self.fl.local_epochs):
-            for x, y in client_batches(ds, self.fl.batch_size,
-                                       rnd_seed * 131 + ep):
-                xs.append(x)
-                ys.append(y)
-        return np.stack(xs), np.stack(ys)          # (T, B, ...), (T, B)
+    def _client_epoch_rows(self, client_id: int, rnd_seed: int):
+        """Sample-table rows of all local-training batches for one
+        client, (T, B) int32: the stream of the looped ``local_train``
+        (same seeds, same order, same dropped tail)."""
+        part = self._parts[client_id]
+        return np.concatenate([
+            part[epoch_batch_rows(len(part), self.fl.batch_size,
+                                  rnd_seed * 131 + ep)]
+            for ep in range(self.fl.local_epochs)]).astype(np.int32)
+
+    def _gather_batches(self, table_x, table_y, rows):
+        """rows (C, T, B) -> the batches xs (C, T, B, H, W, ch), ys.
+
+        A program of its own, ahead of the training program: the
+        training program then compiles as it does for batches copied
+        from the host, and trains bit-identically to it (fused into it,
+        the gather changed the 16-row program's rounding on a TPU v5e).
+        """
+        xs = table_x[rows].reshape(rows.shape + self._sample_shape)
+        return xs, table_y[rows]
+
+    def _resident_tables(self, run):
+        """The sample table as ``run`` reads it: the copy made at init,
+        or one replicated over the mesh of a client-sharded runner
+        (its ``replicated_sharding``), made on first use."""
+        sharding = getattr(run, "replicated_sharding", None)
+        if sharding not in self._tables:
+            self._tables[sharding] = jax.device_put(self._tables[None],
+                                                    sharding)
+        return self._tables[sharding]
 
     def _batch_train_impl(self, params, xs, ys):
         """xs (C, T, B, H, W, ch), ys (C, T, B) -> stacked params (C, ...).
@@ -111,33 +145,37 @@ class CNNTrainer:
             return p
         return jax.vmap(one_client)(xs, ys)
 
-    def _bucketed_train(self, keys, train_chunk):
+    def _bucketed_train(self, keys, train_chunk, wrap):
         """Shared shape-bucketing for the batched paths: build each
-        (client, seed)-keyed batch stream once, bucket positions by
-        stream shape (ragged partitions), run ``train_chunk(xs, ys,
-        positions)`` per bucket, and reassemble chunk rows in input
-        order.  Spans: ``train.batches`` (streams and stacks, on the
-        host), ``train.h2d`` (the copy to the device), ``train.dispatch``
-        (the bucket programs)."""
+        (client, seed)-keyed stream of table rows once, bucket positions
+        by stream shape (ragged partitions), gather each bucket's
+        batches on the device, run ``train_chunk(xs, ys, positions)``
+        per bucket, and reassemble chunk rows in input order.  ``wrap``
+        (see ``local_train_batch``) runs the gather too, with the two
+        sample tables replicated.  Spans: ``train.batches`` (row streams
+        and stacks, on the host), ``train.h2d`` (the rows' copy to the
+        device), ``train.dispatch`` (the gather and bucket programs)."""
+        gather = (self._gather if wrap is None
+                  else wrap(self._gather_batches, 2))
+        table_x, table_y = self._resident_tables(gather)
         tel = obs.TEL
         with tel.span("train.batches") as span:
             data = {}                 # pad slots repeat (client, seed)
             buckets: Dict[tuple, List[int]] = {}
             for pos, key in enumerate(keys):
                 if key not in data:   # keys, so compute each stream once
-                    data[key] = self._client_epoch_batches(*key)
-                buckets.setdefault(data[key][0].shape, []).append(pos)
-            host = [(np.stack([data[keys[p]][0] for p in positions]),
-                     np.stack([data[keys[p]][1] for p in positions]))
+                    data[key] = self._client_epoch_rows(*key)
+                buckets.setdefault(data[key].shape, []).append(pos)
+            host = [np.stack([data[keys[p]] for p in positions])
                     for positions in buckets.values()]
             if tel.enabled:
                 span.set(streams=len(data))
-        nbytes = sum(xs.nbytes + ys.nbytes for xs, ys in host)
+        nbytes = sum(rows.nbytes for rows in host)
         with tel.span("train.h2d", bytes=nbytes):
-            dev = [(jnp.asarray(xs), jnp.asarray(ys)) for xs, ys in host]
+            dev = [jnp.asarray(rows) for rows in host]
         with tel.span("train.dispatch"):
-            chunks = [train_chunk(xs, ys, positions) for (xs, ys), positions
-                      in zip(dev, buckets.values())]
+            chunks = [train_chunk(*gather(table_x, table_y, rows), positions)
+                      for rows, positions in zip(dev, buckets.values())]
         if len(chunks) == 1:          # common case: one shape bucket,
             return chunks[0]          # order already the input order
         order = [p for positions in buckets.values() for p in positions]
@@ -164,7 +202,7 @@ class CNNTrainer:
                else wrap(self._batch_train_impl, 1))
         stacked = self._bucketed_train(
             [(c, rnd_seed) for c in client_ids],
-            lambda xs, ys, positions: run(params, xs, ys))
+            lambda xs, ys, positions: run(params, xs, ys), wrap)
         return stacked, sizes
 
     # -- per-client start params (async runtime hot path) ---------------
@@ -206,7 +244,7 @@ class CNNTrainer:
             return run(starts, xs, ys)
 
         stacked = self._bucketed_train(list(zip(client_ids, rnd_seeds)),
-                                       chunk)
+                                       chunk, wrap)
         return stacked, sizes
 
     def evaluate(self, params, max_samples: int = 2048) -> float:

@@ -59,6 +59,7 @@ GAUGES = frozenset({
     "store.bytes_hot", "store.bytes_cold",
     "fl.population", "fl.tier.count", "fl.tier.size",
     "fl.tier.threshold_s",
+    "train.resident_bytes",
 })
 
 HISTS = frozenset({

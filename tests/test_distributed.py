@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.config import get_arch
 from repro.config.base import FLConfig
@@ -456,22 +458,29 @@ def test_cohort16_trains_sharded_and_matches_single_device():
 
 
 @multi_device
-def test_train_clients_sharded_uneven_cohort_matches():
-    """Sync path (shared global params, replicated arg) with a cohort
-    smaller than the mesh."""
+@pytest.mark.parametrize("ids", [[0, 1, 2], [0, 1, 2, 3, 4]])
+def test_train_clients_sharded_uneven_cohort_matches(ids):
+    """Sync path (replicated args: the shared global params and the
+    trainer's two sample tables) with a cohort smaller than the mesh
+    and one padded to a pow2 bucket; the tables are replicated over
+    the mesh once, not on every round."""
     tr, _, fl = _setup()
     mesh = make_client_mesh()
     sharded = make_engine(tr, mesh=mesh)
     plain = make_engine(tr)
     params = tr.init_params(0)
-    s_stacked, s_sizes = sharded.train_clients(params, [0, 1, 2], 1)
-    p_stacked, p_sizes = plain.train_clients(params, [0, 1, 2], 1)
-    np.testing.assert_array_equal(s_sizes, p_sizes)
-    for a, b in zip(jax.tree_util.tree_leaves(s_stacked),
-                    jax.tree_util.tree_leaves(p_stacked)):
-        assert a.shape[0] == 3
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32), atol=5e-5)
+    for rnd_seed in (1, 2):
+        s_stacked, s_sizes = sharded.train_clients(params, ids, rnd_seed)
+        p_stacked, p_sizes = plain.train_clients(params, ids, rnd_seed)
+        np.testing.assert_array_equal(s_sizes, p_sizes)
+        for a, b in zip(jax.tree_util.tree_leaves(s_stacked),
+                        jax.tree_util.tree_leaves(p_stacked)):
+            assert a.shape[0] == len(ids)
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32), atol=5e-5)
+    replicated = NamedSharding(mesh, P())
+    assert set(tr._tables) == {None, replicated}
+    assert all(t.sharding == replicated for t in tr._tables[replicated])
 
 
 @multi_device

@@ -10,6 +10,7 @@ import glob
 import os
 import time
 
+import jax.numpy as jnp
 import pytest
 
 from repro import obs
@@ -52,6 +53,9 @@ def test_round_spans_hold_their_phases_in_order(setup):
                   "n_stragglers"):
         assert getattr(h_on, field) == getattr(h_off, field), field
 
+    (size,) = {len(c) for c in trainer.clients}
+    rows_per_client = fl.local_epochs * (size // fl.batch_size
+                                         ) * fl.batch_size
     rounds = [s for s in tel.spans if s["name"] == "round"]
     assert [s["args"]["rnd"] for s in rounds] == h_on.rounds
     merged = [n - k for n, k in zip(h_on.n_selected, h_on.n_stragglers)]
@@ -74,9 +78,27 @@ def test_round_spans_hold_their_phases_in_order(setup):
             assert a["ts_us"] + a["dur_us"] <= b["ts_us"]
         if n:
             assert kids[0]["args"]["streams"] == n
-            assert kids[1]["args"]["bytes"] > 0
+            # only the cohort's sample-table rows cross, int32, no labels
+            assert kids[1]["args"]["bytes"] == (1 << (n - 1).bit_length()
+                                                ) * rows_per_client * 4
     # the round spans cover the virtual clock the history records
     assert rounds[-1]["vt1"] == pytest.approx(h_on.times[-1])
+
+
+def test_resident_bytes_gauge_recorded_once(setup):
+    """The sample table lands on the device once, at the trainer's
+    start, as one flat f32 row per sample and int32 labels; a traced
+    run records its bytes once and no round records them again."""
+    trainer, fl = setup
+    with obs.tracing() as tel:
+        tr = CNNTrainer(trainer.cfg, fl, "mnist", scale=0.01)
+        _run(tr, fl)
+    table_x, table_y = tr._resident_tables(None)
+    n = sum(len(c) for c in tr.clients)
+    assert table_x.shape == (n, tr.clients[0].x[0].size)
+    assert (table_x.dtype, table_y.dtype) == (jnp.float32, jnp.int32)
+    points = tel.gauge_series["train.resident_bytes"]
+    assert [v for _, v in points] == [table_x.nbytes + table_y.nbytes]
 
 
 def test_spans_land_on_the_profiler_host_plane(setup, tmp_path):
