@@ -25,6 +25,21 @@ def fedagg_window_bytes(round_updates: Sequence[int], p: int) -> int:
     return sum(fedagg_bytes(n, p) for n in round_updates if n >= 1)
 
 
+def fedagg_fold_bytes(rows: int, p: int) -> int:
+    """HBM bytes one folded staleness merge (``fedagg_fold``) of ``rows``
+    real client rows into the global row needs: each real row and the
+    global read once, one merged row written.  Padded rows carry a zero
+    coefficient and are not counted."""
+    return (rows + 2) * p * F32
+
+
+def fold_window_bytes(round_updates: Sequence[int], p: int) -> int:
+    """Needed bytes of every fold of the window's semi-async rounds: a
+    round that merges two rows or more folds them in one kernel pass; a
+    round of one row takes the singleton merge and no kernel."""
+    return sum(fedagg_fold_bytes(n, p) for n in round_updates if n >= 2)
+
+
 def flops_per_sample(cfg: dict) -> int:
     return ref.model_module(cfg["model"]).flops_per_sample(cfg["sizes"])
 

@@ -4,7 +4,9 @@ from __future__ import annotations
 
 from chipbench import trace
 
-TRAIN_PROGRAMS = ("_batch_train_impl",)
+# the sync and singleton program, and the cohort program from per-client
+# start models (semi-async windows)
+TRAIN_PROGRAMS = ("_batch_train_impl", "_batch_train_multi_impl")
 
 
 def span_ms_per_round(ctx, names) -> float | None:

@@ -1,5 +1,7 @@
 """Device milliseconds per round of the vmapped cohort-training
-programs, on the busiest device of the trace."""
+programs (``_batch_train_impl``, ``_batch_train_multi_impl``), on the
+busiest device of the trace.  The gather of their batches from the
+sample table (``_gather_batches``) is not counted."""
 
 from chipbench.metrics._common import TRAIN_PROGRAMS, busiest_ns
 

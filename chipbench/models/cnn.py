@@ -4,8 +4,10 @@
     -> relu -> maxpool 2x2 -> flatten (H, W, C order) -> FC + relu ...
     -> FC(n_classes)
 
-Straight ``jax.numpy`` / ``lax`` with an explicit dtype and matmul
-precision, no kernels and no batching tricks.  The parameter pytree has
+``init``, ``forward``, ``loss`` and ``flops_per_sample`` are what the
+benchmark asks of every model module.  Straight ``jax.numpy`` / ``lax``
+with an explicit dtype and matmul precision, no kernels and no batching
+tricks.  The parameter pytree has
 the layout the served trainer takes (``{"convs": [{"w", "b"}, ...],
 "fcs": [{"w", "b"}, ...]}``, conv kernels HWIO), so the benchmark can
 hand the weights it makes to both sides.
@@ -60,6 +62,14 @@ def forward(sizes: dict, params, x, precision):
         if i < len(params["fcs"]) - 1:
             x = jnp.maximum(x, 0)
     return x
+
+
+def loss(sizes: dict, params, x, y, precision):
+    """Mean cross-entropy of ``forward``'s logits against labels y (B,)."""
+    logits = forward(sizes, params, x, precision)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+    return jnp.mean(lse - gold)
 
 
 def flops_per_sample(sizes: dict) -> int:

@@ -2,18 +2,23 @@
 ``correct``.
 
 Nothing here imports the program.  The reference is given what the
-window's round took in: the global model entering the round, the ids
-and data-stream seeds of the clients it trained, and the federation's
-hyper-parameters; each client's samples come from the benchmark's own
-copy of the data (``chipbench.data``).  From those it trains each
-client by plain Adam in float32 at the matmul precision the
-configuration states (``default``: one bfloat16 pass on a TPU, as the
-program runs; 120 Adam steps amplify any difference about 10^4-fold, so
-a reference at ``highest`` could not tell a fault from rounding) and
-merges the trained rows as FedDCT defines the sync merge: the
-sample-weighted mean of the survivors' models.  A client's batches are
-the program's stream: per epoch a permutation drawn from
-``seed * 131 + epoch``, the ragged tail dropped.
+window's round took in: the global model entering the round, the ids,
+data-stream seeds and start models of the clients it trained, and the
+federation's hyper-parameters; each client's data comes from the
+benchmark's own copy (the configuration's module under
+``chipbench/datasets/``).  From those it trains each client by plain
+Adam on the model module's ``loss``, in float32 at the matmul precision
+the configuration states (``default``: one bfloat16 pass on a TPU, as
+the program runs; 120 Adam steps amplify any difference about
+10^4-fold, so a reference at ``highest`` could not tell a fault from
+rounding), and merges the trained rows as the traffic's ``merge``
+names it:
+
+* ``sync_mean``: FedDCT's sync merge, the sample-weighted mean of the
+  survivors' models;
+* ``staleness_fold``: the semi-async merge, applied row by row in merge
+  order in float64, ``g <- (1 - a_i) g + a_i x_i`` with
+  ``a_i = alpha (s_i + 1)^-a`` (``poly``) or ``alpha`` (``constant``).
 
 The numbers compared (each against its limit in ``cells/<cell>.json``):
 
@@ -33,8 +38,12 @@ The numbers compared (each against its limit in ``cells/<cell>.json``):
   program's merged global and the reference's merge of the program's
   trained rows, over the larger of that leaf's and the median leaf's
   largest reference value.
-* ``data_gap``: the clients whose samples the program's trainer holds
-  otherwise than the benchmark's copy of the data.  Exact: limit 0.
+* ``data_gap``: the clients whose data the program's trainer holds
+  otherwise than the benchmark's copy.  Exact: limit 0.
+* ``start_gap`` (traffic that trains clients from a snapshot taken at
+  their selection): the merged clients whose start model is not
+  bitwise the global model at the boundary of the round that selected
+  them.  Exact: limit 0.
 """
 
 from __future__ import annotations
@@ -62,28 +71,6 @@ def model_module(name: str):
     return importlib.import_module(f"chipbench.models.{name}")
 
 
-def client_stream(x: np.ndarray, y: np.ndarray, batch: int, epochs: int,
-                  seed: int):
-    """A client's local batches for one round: per epoch a permutation
-    drawn from ``seed * 131 + epoch``, cut into full batches (the ragged
-    tail is dropped).  -> xs (T, B, ...), ys (T, B)."""
-    xs, ys = [], []
-    for ep in range(epochs):
-        idx = np.random.default_rng(seed * 131 + ep).permutation(len(y))
-        for b in range(max(len(y) // batch, 1)):
-            sl = idx[b * batch:(b + 1) * batch]
-            xs.append(x[sl])
-            ys.append(y[sl])
-    return np.stack(xs), np.stack(ys)
-
-
-def _loss(model, sizes, params, x, y, precision):
-    logits = model.forward(sizes, params, x, precision)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-    return jnp.mean(lse - gold)
-
-
 def make_train(model, sizes: dict, lr: float, dtype=jnp.float32,
                precision=HIGHEST, half_batch: bool = False):
     """Jitted Adam over a client's batches, vmapped over clients:
@@ -92,7 +79,7 @@ def make_train(model, sizes: dict, lr: float, dtype=jnp.float32,
     ``dtype``.  ``half_batch`` trains on the first half of each batch
     (a planted fault, for calibration)."""
     b1, b2, eps = 0.9, 0.999, 1e-8
-    grad = jax.grad(lambda p, x, y: _loss(model, sizes, p, x, y, precision))
+    grad = jax.grad(lambda p, x, y: model.loss(sizes, p, x, y, precision))
 
     def one(p0, xs, ys):
         p = jax.tree_util.tree_map(lambda a: a.astype(dtype), p0)
@@ -129,7 +116,7 @@ def make_train(model, sizes: dict, lr: float, dtype=jnp.float32,
 
 def make_grad_norms(model, sizes: dict):
     """Jitted per-leaf norms of the reference's first-step gradient."""
-    grad = jax.grad(lambda p, x, y: _loss(model, sizes, p, x, y, HIGHEST))
+    grad = jax.grad(lambda p, x, y: model.loss(sizes, p, x, y, HIGHEST))
 
     def fn(p, x, y):
         g = grad(p, x, y)
@@ -185,6 +172,49 @@ def sync_merge(rows: Sequence[List[np.ndarray]], sizes: Sequence[float]):
     w = w / w.sum()
     return [sum(wi * r[j] for wi, r in zip(w, rows))
             for j in range(len(rows[0]))]
+
+
+def staleness_alphas(fed: dict, stalenesses: Sequence[int]) -> List[float]:
+    """Per-row merge weights of the semi-async merge from the
+    federation's ``async_alpha``, ``async_staleness`` and ``async_a``."""
+    alpha = float(fed["async_alpha"])
+    if fed["async_staleness"] == "poly":
+        return [alpha * (s + 1.0) ** (-float(fed["async_a"]))
+                for s in stalenesses]
+    return [alpha] * len(stalenesses)
+
+
+def staleness_fold(g_in, rows, alphas):
+    """``g <- (1 - a_i) g + a_i x_i`` over the rows in merge order, in
+    float64."""
+    g = [np.asarray(l, np.float64) for l in g_in]
+    for a, r in zip(alphas, rows):
+        g = [(1.0 - a) * gl + a * rl for gl, rl in zip(g, r)]
+    return g
+
+
+def staleness_fold_in(dtype, g_in, rows, alphas):
+    """``staleness_fold`` with every array and weight in ``dtype``."""
+    g = [jnp.asarray(l, dtype) for l in g_in]
+    for a, r in zip(alphas, rows):
+        a = jnp.asarray(a, dtype)
+        g = [(1 - a) * gl + a * jnp.asarray(rl, dtype)
+             for gl, rl in zip(g, r)]
+    return [np.asarray(l.astype(jnp.float32), np.float64) for l in g]
+
+
+def merge(kind: str, g_in, rows, weights, alphas, dtype=None):
+    """The traffic's reference merge (module docstring) of ``rows`` into
+    ``g_in``: ``weights`` are the sample counts (``sync_mean``),
+    ``alphas`` the per-row staleness weights (``staleness_fold``).
+    ``dtype`` computes it in that precision instead of float64."""
+    if kind == "sync_mean":
+        return (sync_merge(rows, weights) if dtype is None
+                else sync_merge_in(dtype, rows, weights))
+    if kind == "staleness_fold":
+        return (staleness_fold(g_in, rows, alphas) if dtype is None
+                else staleness_fold_in(dtype, g_in, rows, alphas))
+    raise KeyError(f"no reference merge {kind!r}")
 
 
 def sync_merge_in(dtype, rows, sizes):

@@ -1,9 +1,11 @@
 """The benchmark's data: ``BENCHMARK.json`` and the files it names.
 
 Each configuration is ``configs/<config>.json`` with its plain
-reference model in ``models/<model>.py``; each traffic mix is
-``traffic/<traffic>.json``; each cell's correctness limits are
-``cells/<cell>.json``; each per-layer metric is ``metrics/<name>.py``.
+reference model in ``models/<model>.py`` and its data in
+``datasets/<data>.py``; each traffic mix is ``traffic/<traffic>.json``;
+each cell's correctness limits are ``cells/<cell>.json``; each
+per-layer metric is ``metrics/<name>.py``; each runner's warm-up is
+``warmups/<method>.py``.
 The harness finds every file by the name ``BENCHMARK.json`` gives, so
 a new cell, mix, configuration or metric is a new file and an entry,
 and no edit of a file that is there.
@@ -46,6 +48,10 @@ def limits(cell_name: str) -> dict:
 
 def metric_reader(name: str):
     return importlib.import_module(f"chipbench.metrics.{name}")
+
+
+def warm_up(method: str):
+    return importlib.import_module(f"chipbench.warmups.{method}")
 
 
 def peaks(device_kind: str) -> dict:

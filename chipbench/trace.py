@@ -15,6 +15,7 @@ times are nanoseconds on the trace's clock.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 from typing import Dict, Iterable, List, Sequence, Tuple
@@ -134,13 +135,32 @@ def ops_within(ops, mods, module_needles: Sequence[str], op_needle: str,
     return sum(length(clip(inside, a, b)) for a, b in spans)
 
 
+def ops_by_program(ops, mods, op_needle: str, t0: int, t1: int
+                   ) -> Dict[str, int]:
+    """Nanoseconds of ops whose text contains ``op_needle``, by the name
+    of the program each runs inside (the one whose event holds its
+    start)."""
+    inside = sorted((s, e) for n, s, e in ops if op_needle in n)
+    if not inside:
+        return {}
+    starts = [s for s, _ in inside]
+    out: Dict[str, int] = {}
+    for name, a, b in mods:
+        lo, hi = bisect.bisect_left(starts, a), bisect.bisect_left(starts, b)
+        ns = length(clip(inside[lo:hi], max(a, t0), min(b, t1)))
+        if ns > 0:
+            out[name] = out.get(name, 0) + ns
+    return out
+
+
 KERNEL_PROGRAMS = ("_fedagg_call",)
 
 
 def reduce_device(plane, t0: int, t1: int) -> dict:
     """One device's numbers over the window ``[t0, t1)``: busy time,
-    idle gaps, time per op and per program name, and the time of the
-    Pallas merge kernels (the custom calls inside the fedagg programs)."""
+    idle gaps, time per op and per program name, the time of the Pallas
+    merge kernels (the custom calls inside the fedagg programs) and the
+    custom calls' time by the program they run in."""
     ops = line_events(plane, OPS_LINE)
     mods = line_events(plane, MODULES_LINE, fallback=False)
     busy = union(clip(((s, e) for _, s, e in ops), t0, t1))
@@ -151,7 +171,9 @@ def reduce_device(plane, t0: int, t1: int) -> dict:
                                   t0, t1),
             "modules_ns": sum_by_name(mods, t0, t1),
             "fedagg_kernel_ns": ops_within(ops, mods, KERNEL_PROGRAMS,
-                                           "custom-call", t0, t1)}
+                                           "custom-call", t0, t1),
+            "custom_call_ns": ops_by_program(ops, mods, "custom-call", t0,
+                                             t1)}
 
 
 def time_in(names_ns: Dict[str, int], needles: Sequence[str]) -> int:

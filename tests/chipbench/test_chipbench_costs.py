@@ -62,6 +62,30 @@ def test_fedagg_bytes_count_real_rows():
     assert harness.RoundRecorder.distinct([4, 9, 2, 2, 2, 2, 2, 2]) == 3
 
 
+def test_fedagg_fold_bytes_count_real_rows_and_the_global():
+    """A fold reads each real row and the global once and writes one
+    row; a round of one row merges without the kernel and counts
+    nothing; padded rows count nothing."""
+    p = 1_630_090
+    assert costs.fedagg_fold_bytes(5, p) == 7 * p * 4
+    assert costs.fold_window_bytes([5, 0, 1, 2], p) == (7 + 4) * p * 4
+    assert costs.fold_window_bytes([1, 1, 0], p) == 0
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_reference_loss_is_the_cross_entropy_of_forward(name):
+    cfg = _config(name)
+    model = ref.model_module(cfg["model"])
+    params = harness.make_weights(cfg, 3)
+    h, w, c = cfg["sizes"]["input_hw"]
+    x = jax.random.normal(jax.random.PRNGKey(2), (4, h, w, c))
+    y = jnp.asarray([0, 3, 9, 3], jnp.int32)
+    logits = model.forward(cfg["sizes"], params, x, ref.HIGHEST)
+    want = -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(4), y])
+    got = model.loss(cfg["sizes"], params, x, y, ref.HIGHEST)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+
+
 @pytest.mark.parametrize("name", CONFIGS)
 def test_reference_forward_matches_program(name):
     from repro.config import get_arch

@@ -1,12 +1,12 @@
-"""The benchmark's copy of the clients' data draws what the program's
-generator and partition draw today, and ``data_gap`` counts the clients
-whose samples differ."""
+"""The benchmark's copy of the clients' image data draws what the
+program's generator and partition draw today, and ``data_gap`` counts
+the clients whose samples differ."""
 
 import numpy as np
 import pytest
 from chipbench_tiny import ROOT  # noqa: F401  (puts the repo on sys.path)
 
-from chipbench import data
+from chipbench.datasets import images as data
 
 
 @pytest.mark.parametrize("seed,scale", [(0, 0.02), (7, 0.05),
@@ -37,14 +37,19 @@ class _Client:
         self.x, self.y = x, y
 
 
+class _Trainer:
+    def __init__(self, clients):
+        self.clients = clients
+
+
 def test_data_gap_counts_clients_that_differ():
     cfg = {"dataset": "mnist", "federation_seed": 4, "data_scale": 0.02,
            "federation": {"n_clients": 6, "primary_frac": 0.7}}
     ours = data.clients(cfg)
     same = [_Client(x.copy(), y.copy()) for x, y in ours]
-    assert data.data_gap(same, ours) == 0
+    assert data.data_gap(_Trainer(same), ours) == 0
     swapped = same[1:2] + same[:1] + same[2:]
-    assert data.data_gap(swapped, ours) == 2
-    assert data.data_gap(same[:5], ours) == 1
+    assert data.data_gap(_Trainer(swapped), ours) == 2
+    assert data.data_gap(_Trainer(same[:5]), ours) == 1
     same[3].x[0, 0, 0, 0] += 1e-6
-    assert data.data_gap(same, ours) == 1
+    assert data.data_gap(_Trainer(same), ours) == 1

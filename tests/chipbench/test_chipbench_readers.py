@@ -1,8 +1,10 @@
 """The readers of the trainer-input and idle-attribution metrics on a
 synthetic traced part with known spans and idle gaps: the exact
 milliseconds per round, the average over chips, and ``None`` where the
-spans they read are absent; and a traced tiny run on the CPU, whose
-result line carries the trainer-input metrics inside ``train_host_ms``."""
+spans they read are absent; the readers of the semi-async cell's
+programs on synthetic device reductions; and a traced tiny run on the
+CPU, whose result line carries the trainer-input metrics inside
+``train_host_ms``."""
 
 import pytest
 from chipbench_tiny import ROOT, run_tiny, tiny_arch  # noqa: F401
@@ -110,3 +112,56 @@ def test_traced_run_reports_trainer_input(tmp_path, monkeypatch):
     assert {"train_host_ms", "batch_host_ms", "h2d_host_ms"} <= set(m)
     assert (m["batch_host_ms"]["value"] + m["h2d_host_ms"]["value"]
             <= m["train_host_ms"]["value"])
+
+
+# the semi-async cell's programs on two chips, ns in the traced part
+ASYNC_DEVICES = [
+    {"modules_ns": {"jit__batch_train_multi_impl": 30 * MS,
+                    "jit__batch_train_impl": 4 * MS,
+                    "jit__gather_batches": 2 * MS,
+                    "jit_gather_impl": 3 * MS, "jit_gather_one_impl": 1 * MS,
+                    "jit_scatter_params_impl": 2 * MS,
+                    "jit__fedagg_fold_call": 1 * MS, "jit__eval_impl": 9 * MS},
+     "custom_call_ns": {"jit__fedagg_fold_call": 800_000,
+                        "jit__fedagg_call": 5 * MS}},
+    {"modules_ns": {"jit__batch_train_multi_impl": 10 * MS},
+     "custom_call_ns": {"jit__fedagg_fold_call": 400_000}},
+]
+
+
+def async_context(devices=ASYNC_DEVICES, round_updates=(3, 1, 0, 2)):
+    return harness.Context(cell={}, rounds=4, window_s=0.5,
+                           round_updates=list(round_updates), spans=[],
+                           devices=devices, peaks={"hbm_bytes_per_s": 819e9},
+                           chips=len(devices), n_params=1_000_000)
+
+
+def test_train_and_store_device_ms_per_round():
+    """The cohort and singleton training programs (34 ms) and the
+    store's gathers and scatters (6 ms) over 4 rounds, on the busiest
+    chip; the batch gather, the fold and ``evaluate`` belong to
+    neither."""
+    ctx = async_context()
+    assert spec.metric_reader("train_device_ms").read(ctx) == \
+        pytest.approx(8.5)
+    assert spec.metric_reader("store_device_ms").read(ctx) == \
+        pytest.approx(1.5)
+
+
+def test_fold_roofline_counts_fold_rounds_over_fold_kernel_time():
+    """Rounds of 3 and 2 rows fold (5 + 4 rows of 4 MB read or written);
+    the round of one row and the empty one do not; the kernel time is
+    the busiest chip's custom calls inside the fold programs."""
+    got = spec.metric_reader("fold_roofline").read(async_context())
+    need = (5 + 4) * 1_000_000 * 4
+    assert got == pytest.approx(100 * need / (800_000e-9 * 819e9))
+    assert 0 < got <= 100
+
+
+@pytest.mark.parametrize("name", ["train_device_ms", "store_device_ms",
+                                  "fold_roofline"])
+def test_async_reader_none_without_its_programs(name):
+    empty = [{"modules_ns": {"jit__eval_impl": MS}, "custom_call_ns": {}}]
+    reader = spec.metric_reader(name)
+    assert reader.read(async_context(devices=empty)) is None
+    assert reader.read(async_context(devices=[])) is None

@@ -78,6 +78,98 @@ def test_cells(cell):
         len(CELLS)
 
 
+DATA_FUNCTIONS = ("build_trainer", "clients", "n_samples", "client_stream",
+                  "data_gap", "check")
+MODEL_FUNCTIONS = ("init", "forward", "loss", "flops_per_sample")
+
+
+@pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_names_modules_that_provide_what_the_harness_calls(cfg):
+    data = json.loads((ROOT / cfg["file"]).read_text())
+    ds = importlib.import_module(f"chipbench.datasets.{data['data']}")
+    model = importlib.import_module(f"chipbench.models.{data['model']}")
+    for mod, names in ((ds, DATA_FUNCTIONS), (model, MODEL_FUNCTIONS)):
+        missing = [n for n in names if not callable(getattr(mod, n, None))]
+        assert not missing, (mod.__name__, missing)
+    from chipbench import harness
+    assert 1 <= harness.ref_block(data) <= harness.REF_CLIENTS
+
+
+@pytest.mark.parametrize("traffic", sorted({w["traffic"]
+                                            for w in CELLS.values()}))
+def test_traffic_files(traffic):
+    """Every mix names a runner the harness can warm, keyword arguments
+    its warm-up covers, ``FLConfig`` fields that exist and a merge the
+    reference knows."""
+    import dataclasses
+
+    from chipbench import harness
+    from chipbench import reference as ref
+
+    from repro.config.base import FLConfig
+    t = json.loads((ROOT / "chipbench" / "traffic" / f"{traffic}.json")
+                   .read_text())
+    warm = harness.warm_up_module(t)
+    assert t["name"] == traffic and callable(warm.warm)
+    assert set(t.get("runner", {})) <= set(warm.RUNNER_KEYS)
+    fields = {f.name for f in dataclasses.fields(FLConfig)}
+    assert set(t.get("federation", {})) <= fields
+    merge = t.get("merge", "sync_mean")
+    assert merge in ("sync_mean", "staleness_fold")
+    if merge == "staleness_fold":
+        assert {"async_alpha", "async_staleness", "async_a"} <= set(
+            t["federation"])
+        assert ref.staleness_alphas(t["federation"], [0])
+
+
+@pytest.mark.parametrize("traffic,fault", [
+    ({"method": "fedbuff"}, "warmups/fedbuff.py is missing"),
+    ({"method": "feddct", "warm_up": "feddct_tiered"},
+     "warmups/feddct_tiered.py is missing"),
+    ({"method": "feddct", "runner": {"use_kernel_agg": True,
+                                     "store_capacity": 8}},
+     "['store_capacity']")])
+def test_warm_up_refuses_a_runner_it_cannot_warm(traffic, fault):
+    from chipbench import harness
+    with pytest.raises(harness.SetupError, match=re.escape(fault)):
+        harness.warm_up({}, None, None, traffic)
+
+
+def test_traffic_names_its_warm_up_and_its_runner_objects(monkeypatch):
+    """A mix whose runner takes an option that a warm-up of its runner
+    does not cover names a warm-up module of its own, which may build
+    the runner's keyword arguments."""
+    import types
+
+    from chipbench import harness, spec
+    seen = []
+    mod = types.SimpleNamespace(
+        RUNNER_KEYS=("mesh_chips",),
+        warm=lambda cfg, traffic, trainer, params: seen.append(traffic),
+        runner_kwargs=lambda t: {"mesh": ("mesh", t["runner"]["mesh_chips"])})
+    monkeypatch.setattr(spec, "warm_up", lambda name: {"mesh4": mod}[name])
+    t = {"method": "feddct_async", "warm_up": "mesh4",
+         "runner": {"mesh_chips": 4}}
+    harness.warm_up({}, None, None, t)
+    assert seen == [t]
+    assert harness.runner_kwargs(t) == {"mesh": ("mesh", 4)}
+
+
+def test_runner_kwargs_default_to_the_traffic_runner():
+    from chipbench import harness
+    t = {"method": "feddct_async", "runner": {"use_kernel_agg": True}}
+    assert harness.runner_kwargs(t) == {"use_kernel_agg": True}
+
+
+@pytest.mark.parametrize("n_params,block", [(1_630_090, 8), (6_795, 8),
+                                            (40_000_000, 6), (10 ** 9, 1)])
+def test_reference_block_fits_its_adam_state(n_params, block):
+    """As many of 8 clients per reference program as 4 GiB of Adam state
+    (16 bytes a parameter a client) holds, and never none."""
+    from chipbench import harness
+    assert harness.ref_block({"n_params": n_params}) == block
+
+
 def test_at_most_half_the_cells_take_four_chips():
     four = sum(1 for w in CELLS.values() if w["chips"] == 4)
     assert four <= max(1, len(CELLS) // 2)

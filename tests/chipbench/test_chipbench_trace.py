@@ -22,6 +22,22 @@ def test_union_and_gaps_known_intervals():
                              0, 100) == {"a": 25, "b": 10}
 
 
+def test_ops_by_program_sums_each_programs_custom_calls():
+    """Custom calls go to the program whose event holds their start, are
+    clipped to the window, and ops of another kind count nothing."""
+    mods = [("jit__fedagg_fold_call", 0, 100), ("jit_gather_impl", 100, 150),
+            ("jit__fedagg_fold_call", 200, 300)]
+    ops = [("%custom-call.1 = f32[8] custom-call(...)", 10, 40),
+           ("%fusion.2 = f32[8] fusion(...)", 40, 90),
+           ("%custom-call.3 = f32[8] custom-call(...)", 210, 260),
+           ("%custom-call.4 = f32[8] custom-call(...)", 270, 320)]
+    assert trace.ops_by_program(ops, mods, "custom-call", 0, 1000) == {
+        "jit__fedagg_fold_call": 30 + 50 + 30}
+    assert trace.ops_by_program(ops, mods, "custom-call", 20, 250) == {
+        "jit__fedagg_fold_call": 20 + 40}
+    assert trace.ops_by_program(ops[1:2], mods, "custom-call", 0, 1000) == {}
+
+
 def test_label_gaps_by_innermost_open_span():
     spans = [("round", 0, 100), ("select", 10, 30), ("eval", 60, 90)]
     got = trace.label_gaps([(12, 20), (40, 55), (65, 95)], spans, top=3)
