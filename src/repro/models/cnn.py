@@ -131,16 +131,25 @@ def cnn_forward(cfg: ModelConfig, params, images, *, im2col: bool = False):
     conv = _conv_im2col if im2col else _conv
     x = images
     if cfg.resnet:
-        x = conv(x, params["stem"])
+        # named scopes: each op's metadata carries its part of the net,
+        # so a device trace splits the programs' time by part
+        with jax.named_scope("resnet.stem"):
+            x = conv(x, params["stem"])
         for i, blk in enumerate(params["blocks"]):
             stride = 1 if i == 0 else 2
-            h = conv(x, blk["conv1"], stride)
-            h = _norm_act(h, blk["scale1"])
-            h = conv(h, blk["conv2"])
-            sc = x if "proj" not in blk else conv(x, blk["proj"], stride)
-            x = _norm_act(h + sc, blk["scale2"])
-        x = x.mean(axis=(1, 2))
-        return x @ params["fc"]["w"] + params["fc"]["b"]
+            with jax.named_scope(f"resnet.block{i}.conv1"):
+                h = conv(x, blk["conv1"], stride)
+            with jax.named_scope(f"resnet.block{i}.norm1"):
+                h = _norm_act(h, blk["scale1"])
+            with jax.named_scope(f"resnet.block{i}.conv2"):
+                h = conv(h, blk["conv2"])
+            with jax.named_scope(f"resnet.block{i}.shortcut"):
+                sc = x if "proj" not in blk else conv(x, blk["proj"], stride)
+            with jax.named_scope(f"resnet.block{i}.norm2"):
+                x = _norm_act(h + sc, blk["scale2"])
+        with jax.named_scope("resnet.head"):
+            x = x.mean(axis=(1, 2))
+            return x @ params["fc"]["w"] + params["fc"]["b"]
     for cv in params["convs"]:
         x = jax.nn.relu(conv(x, cv["w"]) + cv["b"])
         x = _pool(x)
